@@ -42,6 +42,8 @@ def main() -> None:
                     help="also write results as a JSON artifact")
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+    compile_cache.place_compile_cache()
     from benchmarks import (common, distributed_scaling, table1_compression,
                             table2_conjunctive, table3_bagofwords,
                             table4_positional, table5_beam, table6_serving,

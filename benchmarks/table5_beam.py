@@ -34,6 +34,7 @@ import subprocess
 import sys
 import textwrap
 
+import jax
 import numpy as np
 
 from benchmarks import common
@@ -66,7 +67,7 @@ SHARD_SCRIPT = textwrap.dedent("""
         t0 = time.time(); res = fn(); jax.block_until_ready(res.scores)
         dt = time.time() - t0
         d = res.diagnostics
-        print(f"table5/sharded_DR_or_P{P},{dt/%(nq)d*1e6:.1f},"
+        print(f"table5/sharded_cpu_DR_or_P{P},{dt/%(nq)d*1e6:.1f},"
               f"iters={int(np.sum(d['work']))};pops={int(np.sum(d['pops']))}")
 """)
 
@@ -81,12 +82,14 @@ def run(bench: common.Bench | None = None, *, beams=BEAMS, n_queries: int = 16,
     results = {}
 
     qb = pow2_bucket(n_words)
-    backend = kernel_backend.canonical_backend()
+    device_kind = jax.devices()[0].device_kind
+    lowering = kernel_backend.descent_plan().tag
     block = b.engine.config.block
 
     def attach_roofline(rec: dict, us: float, pops: int, padded: int) -> str:
         rl = roofline.wtbc_query_roofline(
-            backend=backend, measured_us_per_query=us,
+            device_kind=device_kind, lowering=lowering,
+            measured_us_per_query=us,
             pops=pops / n_queries, padded=padded / n_queries,
             q=qb, block=block)
         rec.update(padded=padded,
@@ -94,7 +97,8 @@ def run(bench: common.Bench | None = None, *, beams=BEAMS, n_queries: int = 16,
                    bytes_per_query=rl.bytes_per_query,
                    roofline_model_us=rl.model_us_per_query,
                    roofline_frac=rl.achieved_frac,
-                   roofline_backend=backend)
+                   roofline_device_kind=device_kind,
+                   roofline_lowering=lowering)
         return (f"padded={padded};bytes/q={rl.bytes_per_query:.3g};"
                 f"rl_frac={rl.achieved_frac:.4f}")
 
@@ -142,8 +146,8 @@ def run(bench: common.Bench | None = None, *, beams=BEAMS, n_queries: int = 16,
                                   f"iters={iters};pops={pops};{rl_str}"))
 
     if with_sharded:
-        env = dict(os.environ)
-        env["PYTHONPATH"] = "src"
+        # host devices only: the child never contends for the chip
+        env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
         root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         script = SHARD_SCRIPT % {"docs": shard_docs, "nq": min(n_queries, 8),
                                  "beams": tuple(beams)}

@@ -146,7 +146,7 @@ def run(bench: common.Bench | None = None, *, n_requests: int = 768,
         "obs-enabled pass produced no per-stage attribution"
 
     def _gauges(name: str) -> dict:
-        return {dict(g.labels).get("backend", "?"): g.value
+        return {dict(g.labels).get("device_kind", "?"): g.value
                 for g in reg.find(name)}
 
     roofline = {"bytes_per_query": _gauges("repro_roofline_bytes_per_query"),

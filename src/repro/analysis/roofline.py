@@ -25,8 +25,8 @@ memory traffic — every rank probe reads one counter-block tile plus a
 counter entry, and Algorithm 1 issues ``2 ranks × levels × Q`` probes per
 popped (or padded) beam lane.  ``wtbc_query_roofline`` turns measured
 pops/padded/latency into bytes/query and an achieved-fraction-of-peak
-against the backend's memory bandwidth — the number benchmarks/table5 and
-BENCH_PR8.json report next to each beam cell.
+against the device's HBM bandwidth (``launch/mesh.CHIP_PEAKS``, keyed by
+device kind) — the number benchmarks/table5 reports next to each beam cell.
 """
 from __future__ import annotations
 
@@ -36,7 +36,7 @@ import pathlib
 
 import numpy as np
 
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16, chip_peak
 
 ART = pathlib.Path(__file__).resolve().parents[3] / "artifacts" / "dryrun"
 
@@ -249,22 +249,11 @@ def markdown_table(rows: list[CellRoofline]) -> str:
 # WTBC query-path roofline (DESIGN.md §9)
 # ---------------------------------------------------------------------------
 
-# Memory bandwidth floor per canonical kernel backend.  The TPU number is the
-# v5e HBM constant the training-cell roofline above already uses; the GPU
-# number is an A100-class 2 TB/s; "cpu" is a DDR5-ish 41 GB/s single-socket
-# stream bandwidth — deliberately conservative so the achieved fraction on the
-# CI interpret path reads as an upper bound, not a brag.
-WTBC_MEM_BW: dict[str, float] = {
-    "tpu": HBM_BW,
-    "gpu": 2.0e12,
-    "cpu": 4.1e10,
-}
-
-# Per-rank counter traffic: the TPU lowering DMAs the whole (1, 256) int32
-# superblock counter row next to each tile; the GPU/ref lowerings gather one
-# 4-byte entry.
-WTBC_COUNTER_BYTES: dict[str, float] = {"tpu": 256 * 4.0, "gpu": 4.0,
-                                        "cpu": 4.0}
+# Per-rank counter traffic by descent lowering kind: the TPU kernel DMAs the
+# aligned 8-row group of (256,) int32 counter rows holding the entry; the
+# GPU/ref lowerings gather one 4-byte entry.
+WTBC_COUNTER_BYTES: dict[str, float] = {"tpu": 8 * 256 * 4.0, "gpu": 4.0,
+                                        "ref": 4.0}
 
 
 def wtbc_query_bytes(*, pops: float, padded: float, q: int, block: int,
@@ -287,7 +276,8 @@ def wtbc_query_bytes(*, pops: float, padded: float, q: int, block: int,
 @dataclasses.dataclass
 class WTBCQueryRoofline:
     """Memory-roofline attachment for one table5 beam cell."""
-    backend: str                  # canonical kernel backend the BW came from
+    device_kind: str              # device the bandwidth peak is read for
+    lowering: str                 # descent plan tag (counter-traffic shape)
     bytes_per_query: float
     model_us_per_query: float     # bytes / BW — the memory-bound floor
     measured_us_per_query: float
@@ -295,22 +285,25 @@ class WTBCQueryRoofline:
                                   # small values = launch/loop overhead bound
 
 
-def wtbc_query_roofline(*, backend: str, measured_us_per_query: float,
+def wtbc_query_roofline(*, device_kind: str, lowering: str,
+                        measured_us_per_query: float,
                         pops: float, padded: float, q: int, block: int,
                         levels: int = 3) -> WTBCQueryRoofline:
     """Attach the bytes/query model to a measured per-query latency.
 
-    ``pops``/``padded`` are per-query means (floats are fine); ``backend`` is
-    ``kernels.backend.canonical_backend()`` — it picks both the bandwidth
-    floor and the counter-traffic shape.
+    ``pops``/``padded`` are per-query means (floats are fine);
+    ``device_kind`` (``jax.Device.device_kind``) picks the bandwidth peak
+    from ``launch/mesh.CHIP_PEAKS`` — an unknown kind raises; ``lowering``
+    (``KernelPlan.tag``) picks the counter-traffic shape.
     """
-    cb = WTBC_COUNTER_BYTES.get(backend, 4.0)
+    cb = WTBC_COUNTER_BYTES[lowering.partition(":")[0]]
     bpq = wtbc_query_bytes(pops=pops, padded=padded, q=q, block=block,
                            levels=levels, counter_bytes=cb)
-    bw = WTBC_MEM_BW.get(backend, WTBC_MEM_BW["cpu"])
+    bw = chip_peak(device_kind, "hbm_bw")
     model_us = bpq / bw * 1e6
     frac = model_us / max(measured_us_per_query, 1e-9)
-    return WTBCQueryRoofline(backend=backend, bytes_per_query=bpq,
+    return WTBCQueryRoofline(device_kind=device_kind, lowering=lowering,
+                             bytes_per_query=bpq,
                              model_us_per_query=model_us,
                              measured_us_per_query=measured_us_per_query,
                              achieved_frac=frac)
@@ -324,7 +317,7 @@ def live_wtbc_gauges(rl: WTBCQueryRoofline, reg=None) -> None:
     achieved fraction next to the serving counters (DESIGN.md §10)."""
     import repro.obs as obs
     reg = obs.resolve(reg)
-    labels = {"backend": rl.backend}
+    labels = {"device_kind": rl.device_kind, "lowering": rl.lowering}
     reg.gauge("repro_roofline_bytes_per_query", labels,
               "modelled WTBC bytes moved per query").set(rl.bytes_per_query)
     reg.gauge("repro_roofline_model_us_per_query", labels,

@@ -32,24 +32,12 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import drb as drb_mod
 from repro.core import ranked, scdc, wtbc
 from repro.core.drb import DRBAux
 from repro.core.wtbc import WTBCIndex
-
-
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions: the top-level export (with its
-    ``check_vma`` knob) landed after 0.4.x; older releases ship it as
-    ``jax.experimental.shard_map`` with the knob spelled ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
 
 
 @functools.partial(
@@ -92,16 +80,15 @@ def _stack_bytemaps(maps) -> "wtbc.ByteMap":
             c = np.concatenate([c, np.repeat(c[-1:], max_blocks - c.shape[0], axis=0)])
         counts.append(c)
         lengths.append(np.asarray(m.length))
-    return ByteMap(data=jnp.asarray(np.stack(datas)),
-                   counts=jnp.asarray(np.stack(counts)),
-                   length=jnp.asarray(np.stack(lengths)),
-                   block=maps[0].block)
+    return ByteMap(data=np.stack(datas), counts=np.stack(counts),
+                   length=np.stack(lengths), block=maps[0].block)
 
 
 def build_sharded(doc_tokens: list[np.ndarray], vocab_size: int, n_shards: int,
                   block: int = 4096, with_drb: bool = True,
                   eps: float = 1e-6) -> tuple[ShardedWTBC, scdc.SCDCModel]:
-    """Fit global codes, build + stack per-shard indexes (host side)."""
+    """Fit global codes, build + stack per-shard indexes (host side; the
+    leaves are host arrays until :func:`place` puts them on a mesh)."""
     n_docs = len(doc_tokens)
     doc_len = np.array([len(d) for d in doc_tokens], dtype=np.int64)
     flat = np.concatenate([np.concatenate([d, [0]]) for d in doc_tokens])
@@ -140,14 +127,14 @@ def build_sharded(doc_tokens: list[np.ndarray], vocab_size: int, n_shards: int,
     max_docs = max(int(s.n_docs) for s in shards)
     levels = tuple(_stack_bytemaps([s.levels[L] for s in shards])
                    for L in range(wtbc.MAX_LEVELS))
-    offsets = tuple(jnp.asarray(np.stack([np.asarray(s.offsets[L]) for s in shards]))
+    offsets = tuple(np.stack([np.asarray(s.offsets[L]) for s in shards])
                     for L in range(wtbc.MAX_LEVELS))
 
     def stk(get, pad_fill=None, pad_len=None):
         arrs = [np.asarray(get(s)) for s in shards]
         if pad_len is not None:
             arrs = [_pad_to(a, pad_len, pad_fill) for a in arrs]
-        return jnp.asarray(np.stack(arrs))
+        return np.stack(arrs)
 
     big_n = int(max(int(s.n) for s in shards))
     idx = WTBCIndex(
@@ -175,19 +162,37 @@ def build_sharded(doc_tokens: list[np.ndarray], vocab_size: int, n_shards: int,
             nbits_.append(np.asarray(a.bv.n_bits))
             offs_.append(np.asarray(a.bit_off)); hasbm_.append(np.asarray(a.has_bm))
         aux = DRBAux(
-            bv=BitVec(words=jnp.asarray(np.stack(words_)),
-                      counts=jnp.asarray(np.stack(counts_)),
-                      n_bits=jnp.asarray(np.stack(nbits_))),
-            bit_off=jnp.asarray(np.stack(offs_)),
-            has_bm=jnp.asarray(np.stack(hasbm_)),
-            eps=eps)
+            bv=BitVec(words=np.stack(words_), counts=np.stack(counts_),
+                      n_bits=np.stack(nbits_)),
+            bit_off=np.stack(offs_), has_bm=np.stack(hasbm_), eps=eps)
 
     avg_dl = np.float32(doc_len.sum() / max(n_docs, 1))
-    sharded = ShardedWTBC(idx=idx, aux=aux, doc_base=jnp.asarray(doc_base),
-                          global_df=jnp.asarray(df_global.astype(np.int32)),
-                          global_idf=jnp.asarray(idf_np),
-                          global_avg_dl=jnp.asarray(avg_dl), n_shards=n_shards)
+    sharded = ShardedWTBC(idx=idx, aux=aux, doc_base=doc_base,
+                          global_df=df_global.astype(np.int32),
+                          global_idf=idf_np, global_avg_dl=avg_dl,
+                          n_shards=n_shards)
     return sharded, model
+
+
+def place(sharded: ShardedWTBC, mesh: Mesh,
+          shard_axes: str | tuple[str, ...]) -> ShardedWTBC:
+    """Put a (host-built or restored) sharded index on ``mesh``: every
+    stacked leaf split along ``shard_axes`` so each device holds only its own
+    shard, the global scoring tables replicated — the layout
+    :func:`distributed_topk`'s ``shard_map`` consumes, so no call reshards."""
+    axes = (shard_axes,) if isinstance(shard_axes, str) else tuple(shard_axes)
+    split = NamedSharding(mesh, P(axes if len(axes) > 1 else axes[0]))
+    whole = NamedSharding(mesh, P())
+    put = lambda sharding: (lambda x: jax.device_put(x, sharding))
+    return dataclasses.replace(
+        sharded,
+        idx=jax.tree.map(put(split), sharded.idx),
+        aux=(jax.tree.map(put(split), sharded.aux)
+             if sharded.aux is not None else None),
+        doc_base=jax.device_put(sharded.doc_base, split),
+        global_df=jax.device_put(sharded.global_df, whole),
+        global_idf=jax.device_put(sharded.global_idf, whole),
+        global_avg_dl=jax.device_put(sharded.global_avg_dl, whole))
 
 
 # ---------------------------------------------------------------------------
@@ -331,7 +336,8 @@ def distributed_topk(sharded: ShardedWTBC, words: jnp.ndarray, wmask: jnp.ndarra
                pops, over > 0, certified, bound_out)
         return out + (padded,) if has_pad else out
 
-    fn = _shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     res = fn(sharded, words, wmask, idf)
     docs, scores, n_found, iters, pops, over, certified, bound = res[:8]
     return ranked.DRResult(docs, scores, n_found, iters, pops, over,

@@ -9,7 +9,9 @@ loop stays on-device with no host round trips.
 All operations take and return the state tuple
 ``(scores, payload, size, overflowed)``:
   scores     (cap,)   float32, max-heap ordered prefix [0, size)
-  payload    (cap, P) int32
+  payload    P x (cap,) int32 — one array per payload column (a (cap, P)
+             matrix with P of 2-10 pads its minor axis to the TPU's 128
+             lanes, 13-64x the bytes every sift step moves)
   size       ()       int32
   overflowed ()       bool — any enabled push ever hit a full heap
 
@@ -68,17 +70,17 @@ def lex_argmax(s, d0, d1, valid):
 def _prio_gt(sc, pl, i, j):
     """Heap-internal: element ``i`` strictly precedes element ``j`` under the
     total order, on whatever payload columns this heap carries."""
-    W = pl.shape[1]
+    W = len(pl)
     z = jnp.int32(0)
-    a0, b0 = (pl[i, 0], pl[j, 0]) if W >= 1 else (z, z)
-    a1, b1 = (pl[i, 1], pl[j, 1]) if W >= 2 else (z, z)
+    a0, b0 = (pl[0][i], pl[0][j]) if W >= 1 else (z, z)
+    a1, b1 = (pl[1][i], pl[1][j]) if W >= 2 else (z, z)
     # payload col 1 is d1: *descending* in the order (see module docstring)
     return lex_gt(sc[i], a0, a1, sc[j], b0, b1)
 
 
 class Heap(NamedTuple):
     scores: jnp.ndarray      # (cap,) float32
-    payload: jnp.ndarray     # (cap, P) int32
+    payload: tuple           # P x (cap,) int32, one array per column
     size: jnp.ndarray        # () int32
     overflowed: jnp.ndarray  # () bool
 
@@ -90,7 +92,8 @@ class Heap(NamedTuple):
 def make(cap: int, payload_width: int) -> Heap:
     return Heap(
         scores=jnp.full((cap,), NEG_INF, dtype=jnp.float32),
-        payload=jnp.zeros((cap, payload_width), dtype=jnp.int32),
+        payload=tuple(jnp.zeros((cap,), dtype=jnp.int32)
+                      for _ in range(payload_width)),
         size=jnp.int32(0),
         overflowed=jnp.zeros((), dtype=bool),
     )
@@ -107,7 +110,8 @@ def push(h: Heap, score: jnp.ndarray, pay: jnp.ndarray,
     scores, payload, size, _ = h
     at = jnp.where(enable, size, jnp.int32(0))
     scores = scores.at[at].set(jnp.where(enable, score, scores[at]))
-    payload = payload.at[at].set(jnp.where(enable, pay, payload[at]))
+    payload = tuple(c.at[at].set(jnp.where(enable, pay[k], c[at]))
+                    for k, c in enumerate(payload))
 
     def cond(st):
         i, sc, pl = st
@@ -119,8 +123,7 @@ def push(h: Heap, score: jnp.ndarray, pay: jnp.ndarray,
         par = (i - 1) // 2
         si, sp = sc[i], sc[par]
         sc = sc.at[i].set(sp).at[par].set(si)
-        pi, pp = pl[i], pl[par]
-        pl = pl.at[i].set(pp).at[par].set(pi)
+        pl = tuple(c.at[i].set(c[par]).at[par].set(c[i]) for c in pl)
         return par, sc, pl
 
     i0 = jnp.where(enable, size, jnp.int32(0))
@@ -131,10 +134,10 @@ def push(h: Heap, score: jnp.ndarray, pay: jnp.ndarray,
 def pop(h: Heap) -> tuple[jnp.ndarray, jnp.ndarray, Heap]:
     """Remove and return the max element.  Caller guards ``size > 0``."""
     scores, payload, size, overflowed = h
-    top_s, top_p = scores[0], payload[0]
+    top_s, top_p = scores[0], jnp.stack([c[0] for c in payload])
     last = jnp.maximum(size - 1, 0)
     scores = scores.at[0].set(scores[last]).at[last].set(NEG_INF)
-    payload = payload.at[0].set(payload[last])
+    payload = tuple(c.at[0].set(c[last]) for c in payload)
     size = last
 
     cap = h.cap
@@ -159,8 +162,7 @@ def pop(h: Heap) -> tuple[jnp.ndarray, jnp.ndarray, Heap]:
         c = jnp.where(r_wins, rm, lm)
         si, scc = sc[i], sc[c]
         sc = sc.at[i].set(scc).at[c].set(si)
-        pi, pc = pl[i], pl[c]
-        pl = pl.at[i].set(pc).at[c].set(pi)
+        pl = tuple(col.at[i].set(col[c]).at[c].set(col[i]) for col in pl)
         return c, sc, pl
 
     _, scores, payload = jax.lax.while_loop(cond, body, (jnp.int32(0), scores, payload))

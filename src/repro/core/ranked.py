@@ -194,8 +194,8 @@ def _dr_row_body(st, words, wmask, idf_w, *, idx, S, k, conjunctive):
     # answer *including tie order*, so the emission sequence is the same
     # for every beam width; the rest go back into the heap.
     cs = jnp.concatenate([s_p, hp.scores[:1]])
-    c0 = jnp.concatenate([d0, hp.payload[:1, 0]])
-    c1 = jnp.concatenate([d1, hp.payload[:1, 1]])
+    c0 = jnp.concatenate([d0, hp.payload[0][:1]])
+    c1 = jnp.concatenate([d1, hp.payload[1][:1]])
     cv = jnp.concatenate([multi, (hp.size > 0)[None]])
     j = H.lex_argmax(cs, c0, c1, cv)
     emit = single & (~jnp.any(cv)
@@ -270,7 +270,7 @@ def _anytime_finalize(hp: H.Heap, out_docs, out_scores, n_out, *, k: int,
 
     Returns ``(out_docs, out_scores, n_out, certified (k,), bound ())``.
     """
-    s, d0, d1 = hp.scores, hp.payload[:, 0], hp.payload[:, 1]
+    s, d0, d1 = hp.scores, hp.payload[0], hp.payload[1]
     valid = jnp.arange(hp.cap, dtype=jnp.int32) < hp.size
     single = valid & ((d1 - d0) == 1)
     remaining = valid

@@ -13,9 +13,22 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.wtbc import WTBCIndex
+
+
+def _on_host(*xs) -> bool:
+    """True when the idf inputs are concrete arrays, not values being traced.
+
+    Concrete idf tables are computed on the host in float64 and rounded to
+    float32: the TPU's float32 ``log`` is a fast approximation, off by ~1e-4
+    absolute on the chip — enough to move tf-idf scores past the oracle
+    tolerance.  Traced calls (a core's in-jit default table) keep the jnp
+    formula."""
+    return not any(isinstance(x, jax.core.Tracer) for x in xs)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -25,6 +38,10 @@ class TfIdf:
     dr_compatible: bool = True
 
     def idf(self, idx: WTBCIndex) -> jnp.ndarray:
+        if _on_host(idx.df, idx.n_docs):
+            df = np.maximum(np.asarray(idx.df, np.float64), 1.0)
+            return jnp.asarray(np.log(float(idx.n_docs) / df)
+                               .astype(np.float32))
         df = jnp.maximum(idx.df.astype(jnp.float32), 1.0)
         return jnp.log(idx.n_docs.astype(jnp.float32) / df)
 
@@ -43,6 +60,11 @@ class BM25:
     dr_compatible: bool = False
 
     def idf(self, idx: WTBCIndex) -> jnp.ndarray:
+        if _on_host(idx.df, idx.n_docs):
+            df = np.asarray(idx.df, np.float64)
+            n = float(idx.n_docs)
+            return jnp.asarray(np.log(1.0 + (n - df + 0.5) / (df + 0.5))
+                               .astype(np.float32))
         df = idx.df.astype(jnp.float32)
         n = idx.n_docs.astype(jnp.float32)
         return jnp.log(1.0 + (n - df + 0.5) / (df + 0.5))

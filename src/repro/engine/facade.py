@@ -168,7 +168,8 @@ class SearchEngine:
         """Build a document-sharded engine: one WTBC per device along
         ``shard_axes`` of ``mesh`` (a 1-D mesh over the first ``n_shards``
         local devices when ``mesh`` is omitted), global (s,c)-DC code and
-        global idf so shard scores merge exactly."""
+        global idf so shard scores merge exactly.  Each device receives only
+        its own shard (``distributed.place``)."""
         config = config or EngineConfig()
         doc_tokens, vocab_size = _normalize_docs(docs, vocab_size)
         sharded, model = distributed.build_sharded(
@@ -184,6 +185,7 @@ class SearchEngine:
                                  f"{len(devices)} available devices; pass a mesh")
             mesh = jax.sharding.Mesh(
                 np.array(devices[:n_shards]).reshape(n_shards), axes)
+        sharded = distributed.place(sharded, mesh, shard_axes)
         return cls(_token=_CTOR_TOKEN, config=config, model=model,
                    n_docs=len(doc_tokens), backend="sharded", sharded=sharded,
                    mesh=mesh, shard_axes=shard_axes)
@@ -558,6 +560,50 @@ class SearchEngine:
                   the paths it does not cover (DRB, positional, sharded),
                   so one serving profile can carry it across strategies.
         """
+        c = self._prepare(queries, k=k, mode=mode, strategy=strategy,
+                          measure=measure, budget=budget,
+                          deadline_ms=deadline_ms, sla=sla, window=window,
+                          beam_width=beam_width, df_cap=df_cap, mega=mega)
+        reg = self._obs
+        t0 = time.perf_counter() if reg.enabled else 0.0
+        res = c.ex(*c.args)
+        match_pos = match_len = None
+        if c.key.mode in POSITIONAL_MODES:
+            match_pos, match_len = res.match_pos, res.match_len
+        if reg.enabled:
+            self._record_search(reg, c.key, res, c.key.batch_shape, t0)
+        return SearchResults(docs=res.docs, scores=res.scores,
+                             n_found=res.n_found, work=res.iters, k=c.key.k,
+                             mode=c.key.mode, strategy=c.key.strategy,
+                             measure=c.key.measure.name,
+                             match_pos=match_pos, match_len=match_len,
+                             beam_width=c.key.beam_width,
+                             pops=getattr(res, "pops", None),
+                             overflowed=getattr(res, "overflowed", None),
+                             padded=getattr(res, "padded", None),
+                             certified=getattr(res, "certified", None),
+                             score_bound=getattr(res, "bound", None),
+                             sla=c.sla)
+
+    def lower(self, queries, **kwargs) -> "jax.stages.Lowered":
+        """The lowered program :meth:`search` would run for ``queries`` under
+        the same keyword arguments — e.g. to check which kernels it calls
+        (``lower(...).as_text()``).  Shares the executor cache with
+        ``search``: lowering an executor that has run does not retrace it."""
+        c = self._prepare(queries, **kwargs)
+        return c.ex.lower(*c.args)
+
+    def _prepare(self, queries, *, k: int | None = None, mode: str = "and",
+                 strategy: str = "auto", measure="tfidf",
+                 budget: int | None = None,
+                 deadline_ms: float | None = None,
+                 sla: str | None = None,
+                 window: int | None = None,
+                 beam_width: int | None = None,
+                 df_cap: int | None = None,
+                 mega: bool | None = None) -> types.SimpleNamespace:
+        """Validate and normalise one :meth:`search` call into its executor
+        key, executor and argument tuple (``ex(*args)`` runs it)."""
         k = self.config.default_k if k is None else int(k)
         if k <= 0:
             raise ValueError(f"k must be positive, got {k}")
@@ -652,33 +698,17 @@ class SearchEngine:
                                     beam_width, mega, lowering)
         ex = self._executor(key)
         words, wmask = jnp.asarray(ranks), jnp.asarray(mask)
-        reg = self._obs
-        t0 = time.perf_counter() if reg.enabled else 0.0
-        match_pos = match_len = None
         if mode in POSITIONAL_MODES:
-            res = ex(self.idx, words, wmask, self._idf_table(m),
-                     jnp.int32(window or 0), self._avg_doc_len())
-            match_pos, match_len = res.match_pos, res.match_len
+            args = (self.idx, words, wmask, self._idf_table(m),
+                    jnp.int32(window or 0), self._avg_doc_len())
         elif self.backend == "sharded":
-            res = ex(self._sharded, words, wmask, self._idf_table(m))
+            args = (self._sharded, words, wmask, self._idf_table(m))
         elif strat == "dr":
-            res = ex(self.idx, words, wmask, self._idf_table(m))
+            args = (self.idx, words, wmask, self._idf_table(m))
         else:
-            res = ex(self.idx, self.aux, words, wmask, self._idf_table(m),
-                     self._avg_doc_len())
-        if reg.enabled:
-            self._record_search(reg, key, res, ranks.shape, t0)
-        return SearchResults(docs=res.docs, scores=res.scores,
-                             n_found=res.n_found, work=res.iters, k=k,
-                             mode=mode, strategy=strat, measure=m.name,
-                             match_pos=match_pos, match_len=match_len,
-                             beam_width=beam_width,
-                             pops=getattr(res, "pops", None),
-                             overflowed=getattr(res, "overflowed", None),
-                             padded=getattr(res, "padded", None),
-                             certified=getattr(res, "certified", None),
-                             score_bound=getattr(res, "bound", None),
-                             sla=sla)
+            args = (self.idx, self.aux, words, wmask, self._idf_table(m),
+                    self._avg_doc_len())
+        return types.SimpleNamespace(key=key, ex=ex, args=args, sla=sla)
 
     def _record_search(self, reg: "obs.Registry", key, res, shape, t0):
         """Registry side of one observed search (enabled registries only):
@@ -727,7 +757,8 @@ class SearchEngine:
         if pops is not None and len(pops):
             from repro.analysis import roofline
             rl = roofline.wtbc_query_roofline(
-                backend=kernel_backend.canonical_backend(),
+                device_kind=jax.devices()[0].device_kind,
+                lowering=key.lowering,
                 measured_us_per_query=dt * 1e6 / max(B, 1),
                 pops=float(pops.mean()),
                 padded=float(padded.mean()) if padded is not None else 0.0,

@@ -11,7 +11,7 @@ the policy (DESIGN.md §9):
   ``jax.default_backend()`` is a platform the kernel has a lowering for.
 * **descent dispatch** — ``descent_plan()`` picks the lowering of the fused
   wavelet-descent family: ``tpu`` (``make_async_copy`` tile gathers), ``gpu``
-  (Pallas-on-Triton ``pl.load`` gathers), or ``ref`` (the vectorized pure-jnp
+  (Pallas-on-Triton ``plgpu.load`` gathers), or ``ref`` (the vectorized pure-jnp
   fallback — strictly faster than sequential interpret-mode grids inside a
   search ``while_loop``, so it is the no-accelerator default).
 * **forcing** — tests and the CI gpu-lowering job select a code path that the

@@ -12,7 +12,7 @@ single launch with one grid step per batch row:
   emit     the popped singleton written straight to the row's output slot;
   descend  the Q-word × 3-level WTBC count of the left child, sharing
            ``wavelet_descent._descent_levels`` — the one descent definition —
-           with Q-wide ``pl.load`` tile/counter gathers;
+           with Q-wide ``plgpu.load`` tile/counter gathers;
   score    an in-kernel (Q,)·(Q,) dot, unrolled round-each-product /
            add-left-to-right — the reduction ``einsum('bq,bq->b')`` compiles
            to (a fused ``jnp.dot`` FMA-contracts and drifts 1 ulp);
@@ -21,7 +21,7 @@ single launch with one grid step per batch row:
 The frontier never round-trips: state arrays are input/output aliased, and a
 trip writes only the touched cells (popped slot, ≤2 insert slots, the
 emission slot, five per-row scalars) instead of materializing new (B, cap)
-pools.  Gathers are Triton-style ``pl.load`` with computed flat indices, so
+pools.  Gathers are Triton-style ``plgpu.load`` with computed flat indices, so
 the lowering is GPU (or the Pallas interpreter — how CPU CI runs it); the TPU
 path keeps the jnp mega body around the DMA-gather descent kernel.
 
@@ -38,6 +38,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import triton as plgpu
 
 from repro.core import heap as H
 from repro.kernels import backend
@@ -86,13 +87,13 @@ def _kernel(words_ref, wmask_ref, idfw_ref,
     # so read-after-write inside one trip is coherent.
     del ps_in, p0_in, p1_in, ptf_in, od_in, os_in, no_in, it_in, pp_in, ov_in
     cidx = i * cap + jnp.minimum(lane, cap - 1)
-    s = jnp.where(cmask, pl.load(ps_out, (cidx,)), jnp.float32(NEG_INF))
-    d0v = pl.load(p0_out, (cidx,))
-    d1v = pl.load(p1_out, (cidx,))
-    n_out = pl.load(no_out, (i,))
-    iters = pl.load(it_out, (i,))
-    pops = pl.load(pp_out, (i,))
-    ov = pl.load(ov_out, (i,))
+    s = jnp.where(cmask, plgpu.load(ps_out.at[cidx]), jnp.float32(NEG_INF))
+    d0v = plgpu.load(p0_out.at[cidx])
+    d1v = plgpu.load(p1_out.at[cidx])
+    n_out = plgpu.load(no_out.at[i])
+    iters = plgpu.load(it_out.at[i])
+    pops = plgpu.load(pp_out.at[i])
+    ov = plgpu.load(ov_out.at[i])
 
     active = (n_out < k) & jnp.any(s > NEG_INF)
     if max_pops is not None:
@@ -107,17 +108,17 @@ def _kernel(words_ref, wmask_ref, idfw_ref,
     s_p = _at(s, j)
     d0 = _at(d0v, j)
     d1 = _at(d1v, j)
-    tf = pl.load(ptf_out, (i * cap * Q + j * Q + qlane,))
+    tf = plgpu.load(ptf_out.at[i * cap * Q + j * Q + qlane])
     s = jnp.where((lane == j) & active, jnp.float32(NEG_INF), s)
-    pl.store(ps_out, (i * cap + j,), _at(s, j))
+    plgpu.store(ps_out.at[i * cap + j], _at(s, j))
 
     # ---- emit a popped singleton (slot k is the trash lane)
     single = active & ((d1 - d0) == 1)
     multi = active & ~single
     slot = jnp.where(single & (n_out < k), n_out, k)
     oidx = i * (k + 1) + slot
-    pl.store(od_out, (oidx,), jnp.where(single, d0, pl.load(od_out, (oidx,))))
-    pl.store(os_out, (oidx,), jnp.where(single, s_p, pl.load(os_out, (oidx,))))
+    plgpu.store(od_out.at[oidx], jnp.where(single, d0, plgpu.load(od_out.at[oidx])))
+    plgpu.store(os_out.at[oidx], jnp.where(single, s_p, plgpu.load(os_out.at[oidx])))
     n_out = jnp.minimum(n_out + single.astype(jnp.int32), k)
 
     # ---- split: segment extents from sep_pos, then the fused Q-word descent
@@ -125,20 +126,20 @@ def _kernel(words_ref, wmask_ref, idfw_ref,
     n_docs = nn_ref[1]
 
     def doc_start(d):
-        prev = pl.load(sep_ref, (jnp.maximum(d - 1, 0),))
+        prev = plgpu.load(sep_ref.at[jnp.maximum(d - 1, 0)])
         return jnp.where(d == 0, jnp.int32(0), prev + 1)
 
     mid = (d0 + d1) // 2
     lo1 = doc_start(d0)
     hi1 = jnp.where(mid >= n_docs, n, doc_start(mid))
 
-    wq = pl.load(words_ref, (i * Q + qlane,))
-    mq = pl.load(wmask_ref, (i * Q + qlane,))
-    idfw = pl.load(idfw_ref, (i * Q + qlane,))
-    cwb = [pl.load(cwb_ref, (wq * 3 + L,)) for L in range(3)]
-    offq = [pl.load(noff_ref, (wq * 3 + L,)) for L in range(3)]
-    baseq = [pl.load(brank_ref, (wq * 3 + L,)) for L in range(3)]
-    cwl = pl.load(cwl_ref, (wq,))
+    wq = plgpu.load(words_ref.at[i * Q + qlane])
+    mq = plgpu.load(wmask_ref.at[i * Q + qlane])
+    idfw = plgpu.load(idfw_ref.at[i * Q + qlane])
+    cwb = [plgpu.load(cwb_ref.at[wq * 3 + L]) for L in range(3)]
+    offq = [plgpu.load(noff_ref.at[wq * 3 + L]) for L in range(3)]
+    baseq = [plgpu.load(brank_ref.at[wq * 3 + L]) for L in range(3)]
+    cwl = plgpu.load(cwl_ref.at[wq])
     lens = [len_ref[L] for L in range(3)]
     data_refs = (dA, dB, dC)
     count_refs = (cA, cB, cC)
@@ -147,8 +148,8 @@ def _kernel(words_ref, wmask_ref, idfw_ref,
     def level_rank(L, byte, pa, pb):
         def rank1(p):
             blk = jnp.minimum(p // block, n_blocks[L] - 1)
-            tile = pl.load(data_refs[L], (blk[:, None] * block + blane,))
-            cnt = pl.load(count_refs[L], (blk * COUNTER_ROW + byte,))
+            tile = plgpu.load(data_refs[L].at[blk[:, None] * block + blane])
+            cnt = plgpu.load(count_refs[L].at[blk * COUNTER_ROW + byte])
             return cnt + _tile_rank(tile, byte, p, blk, block=block)
         return rank1(pa), rank1(pb)
 
@@ -185,12 +186,11 @@ def _kernel(words_ref, wmask_ref, idfw_ref,
         ok = enable & has_free
         ov = ov | (enable & ~has_free).astype(jnp.int32)
         pidx = i * cap + slot
-        pl.store(ps_out, (pidx,), jnp.where(ok, sc, _at(s, slot)))
-        pl.store(p0_out, (pidx,), jnp.where(ok, da, _at(d0v, slot)))
-        pl.store(p1_out, (pidx,), jnp.where(ok, db, _at(d1v, slot)))
+        plgpu.store(ps_out.at[pidx], jnp.where(ok, sc, _at(s, slot)))
+        plgpu.store(p0_out.at[pidx], jnp.where(ok, da, _at(d0v, slot)))
+        plgpu.store(p1_out.at[pidx], jnp.where(ok, db, _at(d1v, slot)))
         tidx = i * cap * Q + slot * Q + qlane
-        pl.store(ptf_out, (tidx,),
-                 jnp.where(ok, tfv, pl.load(ptf_out, (tidx,))))
+        plgpu.store(ptf_out.at[tidx], jnp.where(ok, tfv, plgpu.load(ptf_out.at[tidx])))
         s = jnp.where((lane == slot) & ok, sc, s)
         d0v = jnp.where((lane == slot) & ok, da, d0v)
         d1v = jnp.where((lane == slot) & ok, db, d1v)
@@ -201,10 +201,10 @@ def _kernel(words_ref, wmask_ref, idfw_ref,
     s, d0v, d1v, ov = insert(s, d0v, d1v, ov, s2, mid, d1, tf2,
                              multi & seg_valid(tf2, s2))
 
-    pl.store(no_out, (i,), n_out)
-    pl.store(it_out, (i,), iters + active.astype(jnp.int32))
-    pl.store(pp_out, (i,), pops + active.astype(jnp.int32))
-    pl.store(ov_out, (i,), ov)
+    plgpu.store(no_out.at[i], n_out)
+    plgpu.store(it_out.at[i], iters + active.astype(jnp.int32))
+    plgpu.store(pp_out.at[i], pops + active.astype(jnp.int32))
+    plgpu.store(ov_out.at[i], ov)
 
 
 def fused_beam_step(idx, words, wmask, idf_w, pool, out_docs, out_scores,

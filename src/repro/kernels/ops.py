@@ -83,7 +83,7 @@ def wavelet_count_batch(levels, cw, cw_len, node_off, base_rank,
     Dispatch via ``backend.descent_plan()``:
 
     * ``tpu`` / ``gpu`` — ONE ``wavelet_descent`` launch (DMA-gather or
-      Triton ``pl.load``-gather lowering) for the whole (M × levels × 2)
+      Triton ``plgpu.load``-gather lowering) for the whole (M × levels × 2)
       rank workload;
     * ``ref`` (no accelerator) — the pure-jnp batched descent, one
       vectorized rank batch per level.  The interpret-mode kernel iterates

@@ -46,6 +46,7 @@ import numpy as np
 import repro.obs as obs
 from repro.engine import SearchEngine
 from repro.engine.facade import MEASURES
+from repro.launch import compile_cache
 from repro.serve import QueryProfile, SearchServer, loadgen, snapshot
 from repro.text import corpus
 
@@ -160,6 +161,7 @@ def main():
                     help="path the periodic/final JSONL snapshots append to "
                          "(default: print to stdout)")
     args = ap.parse_args()
+    compile_cache.place_compile_cache()
 
     metrics_on = (args.metrics or args.metrics_port is not None
                   or args.stats_every > 0)
@@ -262,8 +264,8 @@ def main():
                       f"p95 {d['p95_ms']:.2f}ms  p99 {d['p99_ms']:.2f}ms  "
                       f"(n={d['count']})")
         for g in reg.find("repro_roofline_achieved_frac"):
-            be = dict(g.labels).get("backend", "?")
-            print(f"roofline[{be}]: achieved fraction {g.value:.2e} of the "
+            kind = dict(g.labels).get("device_kind", "?")
+            print(f"roofline[{kind}]: achieved fraction {g.value:.2e} of the "
                   "memory-bandwidth floor")
         emit_snapshot()
         if metrics_http is not None:
@@ -284,6 +286,10 @@ def main():
                    and rep.n_ok == args.requests)
         print(f"smoke: {'PASS' if healthy else 'FAIL'}")
         sys.exit(0 if healthy else 1)
+    if st["errors"]:
+        print(f"error: {st['errors']} requests failed in dispatch",
+              file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -161,8 +161,9 @@ def load(snap_dir: str | pathlib.Path, version: int | None = None, *,
     verify: CRC-check every leaf against the manifest (reads all pages; pass
             ``False`` for the lazy fastest start).
     mmap:   memory-map the arrays instead of reading them eagerly.
-    mesh:   sharded snapshots only — the mesh to place shards on; defaults to
-            a fresh 1-D mesh over the first ``n_shards`` local devices, like
+    mesh:   sharded snapshots only — the mesh to place shards on (each
+            device receives only its own shard); defaults to a fresh 1-D mesh
+            over the first ``n_shards`` local devices, like
             ``SearchEngine.shard`` builds.
     """
     manifest, version = ckpt.read_manifest(snap_dir, version)
@@ -182,7 +183,6 @@ def load(snap_dir: str | pathlib.Path, version: int | None = None, *,
         return SearchEngine._restore(config=config, model=model,
                                      n_docs=meta["n_docs"], backend="single",
                                      idx=idx, aux=aux)
-    sharded = _device_put(state["sharded"])
     axes = meta["shard_axes"]
     shard_axes = tuple(axes) if isinstance(axes, list) else axes
     if mesh is None:
@@ -197,6 +197,7 @@ def load(snap_dir: str | pathlib.Path, version: int | None = None, *,
                              "mesh")
         mesh = jax.sharding.Mesh(
             np.array(devices[:n_shards]).reshape(n_shards), names)
+    sharded = distributed.place(state["sharded"], mesh, shard_axes)
     return SearchEngine._restore(config=config, model=model,
                                  n_docs=meta["n_docs"], backend="sharded",
                                  sharded=sharded, mesh=mesh,

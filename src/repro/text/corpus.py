@@ -48,10 +48,18 @@ def zipf_probs(vocab_size: int, alpha: float) -> np.ndarray:
 def make_corpus(n_docs: int = 2000, mean_doc_len: int = 400,
                 vocab_size: int = 20_000, alpha: float = 1.2,
                 seed: int = 0) -> SyntheticCorpus:
+    """``mean_doc_len`` is the median of the lognormal(sigma=0.6) length
+    law; the arithmetic mean length is ``mean_doc_len * exp(0.18)``."""
     rng = np.random.default_rng(seed)
     lens = np.maximum(2, rng.lognormal(np.log(mean_doc_len), 0.6, n_docs)).astype(np.int64)
-    p = zipf_probs(vocab_size, alpha)
-    docs = [rng.choice(np.arange(1, vocab_size), size=int(l), p=p) for l in lens]
+    # inverse-CDF sampling with the CDF built once: draws exactly what
+    # ``rng.choice(np.arange(1, vocab_size), size=l, p=p)`` draws per document
+    # (same generator stream, same searchsorted), without re-validating and
+    # re-summing the V-long ``p`` for every document
+    cdf = zipf_probs(vocab_size, alpha).cumsum()
+    cdf /= cdf[-1]
+    docs = [1 + cdf.searchsorted(rng.random(int(l)), side="right")
+            for l in lens]
     return SyntheticCorpus(doc_tokens=docs, vocab_size=vocab_size, seed=seed)
 
 

@@ -32,6 +32,7 @@ import pytest
 from test_mega import _sweep_cases
 
 from repro.analysis import roofline
+from repro.launch.mesh import CHIP_PEAKS
 from repro.core import ranked
 from repro.engine import EngineConfig, SearchEngine
 from repro.kernels import backend, ops, ref
@@ -293,17 +294,26 @@ def test_wtbc_query_bytes_model():
 
 
 def test_wtbc_query_roofline_attachment():
-    rl = roofline.wtbc_query_roofline(backend="cpu",
+    rl = roofline.wtbc_query_roofline(device_kind="cpu", lowering="ref",
                                       measured_us_per_query=100.0,
                                       pops=10, padded=2, q=4, block=512)
     assert rl.bytes_per_query == 2 * 3 * 4 * 12 * 516.0
     np.testing.assert_allclose(
         rl.model_us_per_query,
-        rl.bytes_per_query / roofline.WTBC_MEM_BW["cpu"] * 1e6)
+        rl.bytes_per_query / CHIP_PEAKS["cpu"]["hbm_bw"] * 1e6)
     np.testing.assert_allclose(rl.achieved_frac,
                                rl.model_us_per_query / 100.0)
-    # the TPU lowering DMAs the whole counter row next to each tile
-    tpu = roofline.wtbc_query_roofline(backend="tpu",
+    # the TPU lowering DMAs an aligned 8-row counter group next to each
+    # tile, and its floor is the v5e HBM peak
+    tpu = roofline.wtbc_query_roofline(device_kind="TPU v5 lite",
+                                       lowering="tpu",
                                        measured_us_per_query=100.0,
                                        pops=10, padded=2, q=4, block=512)
-    assert tpu.bytes_per_query == 2 * 3 * 4 * 12 * (512 + 1024.0)
+    assert tpu.bytes_per_query == 2 * 3 * 4 * 12 * (512 + 8 * 1024.0)
+    np.testing.assert_allclose(tpu.model_us_per_query,
+                               tpu.bytes_per_query / 819e9 * 1e6)
+    # a device kind without published peaks is an error, not a default
+    with pytest.raises(ValueError, match="no 'hbm_bw' peak"):
+        roofline.wtbc_query_roofline(device_kind="TPU v99", lowering="tpu",
+                                     measured_us_per_query=100.0,
+                                     pops=10, padded=2, q=4, block=512)

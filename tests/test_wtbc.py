@@ -1,6 +1,7 @@
 """WTBC decode/count/locate vs direct token-array oracles."""
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from _hypothesis_shim import given, settings, st
 
 from repro.core import wtbc
@@ -84,3 +85,21 @@ def test_build_properties_random_corpora(seed, n_docs, vocab):
     df = cp.doc_freqs()
     df_ranked = df[np.asarray(model.word_of_rank)]
     assert np.array_equal(np.asarray(idx.df), df_ranked.astype(np.int32))
+
+
+@pytest.mark.parametrize("n_docs,mean_len,vocab,seed",
+                         [(120, 60, 500, 3), (50, 200, 20_000, 7)])
+def test_make_corpus_draws_what_choice_draws(n_docs, mean_len, vocab, seed):
+    """The one-CDF sampler in make_corpus returns exactly the per-document
+    ``rng.choice(..., p=zipf)`` draws it replaced (same generator stream)."""
+    rng = np.random.default_rng(seed)
+    lens = np.maximum(2, rng.lognormal(np.log(mean_len), 0.6,
+                                       n_docs)).astype(np.int64)
+    p = corpus.zipf_probs(vocab, 1.2)
+    want = [rng.choice(np.arange(1, vocab), size=int(n), p=p) for n in lens]
+    got = corpus.make_corpus(n_docs=n_docs, mean_doc_len=mean_len,
+                             vocab_size=vocab, seed=seed).doc_tokens
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+        assert g.dtype == w.dtype
