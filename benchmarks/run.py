@@ -11,8 +11,7 @@ carries the perf trajectory; beam-sweep entries hold iters/pops ratios vs
 P=1, serving entries the table 6 throughput/percentile/cache metrics —
 every serving entry now also carries the queue-wait/service percentile
 split, and the ``open_obs`` entry the registry-derived per-stage latency
-attribution (queue_wait/device/slice/total) plus the live WTBC roofline
-gauges (bytes/query, achieved fraction per kernel backend) — megabatch
+attribution (queue_wait/device/slice/total) fed from the spans — megabatch
 entries the table 7 skew/heavy-band tail latencies for mega vs lockstep vs
 unbatched serving — anytime entries the table 8 budget ladder
 (latency/recall/certified-fraction per rung) plus the served monotone

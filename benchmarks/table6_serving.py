@@ -11,9 +11,9 @@ passes over the shared benchmark engine:
      the batcher must beat at equal client concurrency
   3. open loop at a fixed offered QPS (no cache) — latency under load
   4. closed loop over a Zipf-repeated workload with the cache ON
-  5. open loop with the obs registry ENABLED on a DR profile (pops exist):
-     per-stage latency attribution (queue_wait/device/slice/total) and the
-     live WTBC roofline gauges, straight from the registry (DESIGN.md §10)
+  5. open loop with the obs registry and span recorder ENABLED on a DR
+     profile: per-stage latency attribution (queue_wait/device/slice/total)
+     fed from the spans, straight from the registry (DESIGN.md §10)
 
 The workload is drawn from the selective band (low df, 2 words): the
 interactive regime where per-call host overhead dominates and coalescing
@@ -124,15 +124,14 @@ def run(bench: common.Bench | None = None, *, n_requests: int = 768,
     emit("closed_cached", rep_cache,
          f"hit_rate={rep_cache.server_stats['cache']['hit_rate']:.2f}")
 
-    # -- 5. observability pass: registry stages + live roofline gauges ------
-    # DR profile — the path that reports pops/padded, which is what feeds
-    # the WTBC query-roofline attachment; tfidf keeps 'dr' legal.
+    # -- 5. observability pass: registry stages fed from the spans ---------
     reg = obs.Registry(enabled=True)
     profile_dr = QueryProfile(mode="or", strategy="dr", measure="tfidf",
                               k=10)
     srv_m = SearchServer(engine, max_batch=MAX_BATCH, max_wait_ms=2.0,
                          cache_size=0, queue_depth=4 * WORKERS, registry=reg)
     srv_m.warmup(queries, profile_dr)
+    obs.enable_spans()
     try:
         with srv_m:
             rep_obs = loadgen.open_loop(
@@ -140,24 +139,13 @@ def run(bench: common.Bench | None = None, *, n_requests: int = 768,
                 seed=7)
     finally:
         engine.obs_registry = None      # don't tax later benchmark passes
+        obs.enable_spans(False)
+        obs.SPANS.drain()
     emit("open_obs", rep_obs)
     assert rep_obs.stages and "device" in rep_obs.stages \
         and "queue_wait" in rep_obs.stages, \
         "obs-enabled pass produced no per-stage attribution"
 
-    def _gauges(name: str) -> dict:
-        return {dict(g.labels).get("device_kind", "?"): g.value
-                for g in reg.find(name)}
-
-    roofline = {"bytes_per_query": _gauges("repro_roofline_bytes_per_query"),
-                "model_us_per_query":
-                    _gauges("repro_roofline_model_us_per_query"),
-                "achieved_frac": _gauges("repro_roofline_achieved_frac")}
-    assert roofline["achieved_frac"], "no live roofline gauge was exported"
-    results["open_obs"]["roofline"] = roofline
-    frac = next(iter(roofline["achieved_frac"].values()))
-    print_rows(common.csv_row("table6/open_obs_roofline", 0.0,
-                              f"achieved_frac={frac:.2e}"))
     return results
 
 

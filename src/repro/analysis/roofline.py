@@ -309,27 +309,6 @@ def wtbc_query_roofline(*, device_kind: str, lowering: str,
                              achieved_frac=frac)
 
 
-def live_wtbc_gauges(rl: WTBCQueryRoofline, reg=None) -> None:
-    """Export one measured WTBC query roofline into a :mod:`repro.obs`
-    registry as live gauges (labeled by kernel backend) — the production
-    attachment: the engine facade calls this after each observed search, so
-    a scrape of ``/metrics`` always shows the current bytes/query model and
-    achieved fraction next to the serving counters (DESIGN.md §10)."""
-    import repro.obs as obs
-    reg = obs.resolve(reg)
-    labels = {"device_kind": rl.device_kind, "lowering": rl.lowering}
-    reg.gauge("repro_roofline_bytes_per_query", labels,
-              "modelled WTBC bytes moved per query").set(rl.bytes_per_query)
-    reg.gauge("repro_roofline_model_us_per_query", labels,
-              "memory-bound latency floor (us/query)"
-              ).set(rl.model_us_per_query)
-    reg.gauge("repro_roofline_measured_us_per_query", labels,
-              "measured latency (us/query)").set(rl.measured_us_per_query)
-    reg.gauge("repro_roofline_achieved_frac", labels,
-              "model floor / measured (1.0 = at the memory roofline)"
-              ).set(rl.achieved_frac)
-
-
 def main():
     import argparse
     ap = argparse.ArgumentParser()

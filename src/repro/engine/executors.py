@@ -10,6 +10,11 @@ Trace accounting: each executor's Python body runs only when jax *traces* it
 (once per compilation), so the ``note()`` callback it invokes counts actual
 retraces.  ``SearchEngine.stats["traces"]`` exposes the counters and
 ``tests/test_engine.py`` pins the cache behavior with them.
+
+Naming: each executor's jitted function is named after its path
+(:func:`name_of`: ``dr_or``, ``drb_and``, ``positional_phrase``,
+``sharded_dr_or``, ...), so a device profile's ``XLA Modules`` line reads
+``jit_<name>`` for the program each batch ran.
 """
 from __future__ import annotations
 
@@ -52,6 +57,22 @@ class ExecutorKey(NamedTuple):
                           # beam step (kernels/beam_step.py)
 
 
+def name_of(key: ExecutorKey) -> str:
+    """The executor's name, after its path: ``<strategy>_<mode>`` on the
+    single backend (``_mega`` appended on the pool-frontier core),
+    ``positional_<mode>`` for phrase/near, ``sharded_<strategy>_<mode>``."""
+    if key.backend == "sharded":
+        return f"sharded_{key.strategy}_{key.mode}"
+    if key.mode in ("phrase", "near"):
+        return f"positional_{key.mode}"
+    return f"{key.strategy}_{key.mode}" + ("_mega" if key.mega else "")
+
+
+def _jit(fn, key: ExecutorKey):
+    fn.__name__ = fn.__qualname__ = name_of(key)
+    return jax.jit(fn)
+
+
 def make_single_dr(key: ExecutorKey, *, heap_cap: int, mega_cap: int, note):
     """(idx, words, wmask, idf) -> DRResult with (B, k) leaves."""
     conjunctive = key.mode == "and"
@@ -76,7 +97,7 @@ def make_single_dr(key: ExecutorKey, *, heap_cap: int, mega_cap: int, note):
                                         max_pops=key.budget,
                                         beam_width=key.beam_width)
 
-    return jax.jit(fn)
+    return _jit(fn, key)
 
 
 def make_single_drb(key: ExecutorKey, *, note):
@@ -99,7 +120,7 @@ def make_single_drb(key: ExecutorKey, *, note):
         return jax.vmap(
             lambda w, m: one(idx, aux, w, m, idf, avg_dl))(words, wmask)
 
-    return jax.jit(fn)
+    return _jit(fn, key)
 
 
 def make_single_positional(key: ExecutorKey, *, note):
@@ -115,7 +136,7 @@ def make_single_positional(key: ExecutorKey, *, note):
             idx, words, wmask, idf, k=key.k, phrase=phrase, measure=measure,
             window=window, avg_dl=avg_dl)
 
-    return jax.jit(fn)
+    return _jit(fn, key)
 
 
 def make_sharded(key: ExecutorKey, *, mesh, shard_axes, heap_cap: int, note):
@@ -132,4 +153,4 @@ def make_sharded(key: ExecutorKey, *, mesh, shard_axes, heap_cap: int, note):
             max_df_cap=key.df_cap or 2, max_pops=key.budget,
             measure=key.measure, idf=idf, beam_width=key.beam_width)
 
-    return jax.jit(fn)
+    return _jit(fn, key)

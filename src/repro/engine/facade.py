@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro.obs as obs
+from repro.obs.tracing import SPANS
 from repro.core import distributed, drb, positional, scoring, wtbc
 from repro.engine import executors
 from repro.kernels import backend as kernel_backend
@@ -369,9 +370,8 @@ class SearchEngine:
         """Feed the live us/pop estimator one observed batch: ``seconds`` of
         blocking wall time against the mean per-row pop count (rows run
         vmapped in parallel, so the per-row count is what the wall clock
-        tracks).  Called from the observed search path and from the serving
-        dispatcher; EWMA so bursts move it quickly but one straggler does
-        not poison the estimate."""
+        tracks).  Called from the serving dispatcher; EWMA so bursts move
+        it quickly but one straggler does not poison the estimate."""
         if pops_per_row <= 0 or seconds <= 0:
             return
         us = seconds * 1e6 / float(pops_per_row)
@@ -560,18 +560,32 @@ class SearchEngine:
                   the paths it does not cover (DRB, positional, sharded),
                   so one serving profile can carry it across strategies.
         """
+        sp = SPANS.open("engine.prepare") if SPANS.enabled else None
         c = self._prepare(queries, k=k, mode=mode, strategy=strategy,
                           measure=measure, budget=budget,
                           deadline_ms=deadline_ms, sla=sla, window=window,
                           beam_width=beam_width, df_cap=df_cap, mega=mega)
+        if sp is not None:
+            SPANS.close(sp)
+            name = executors.name_of(c.key)
+            SPANS.tag(executor=name)        # the caller's span (serve.batch)
+            n_traces = self._trace_counts.get(c.key, 0)
+            sp = SPANS.open("engine.launch", attrs={"executor": name})
         reg = self._obs
         t0 = time.perf_counter() if reg.enabled else 0.0
         res = c.ex(*c.args)
+        if sp is not None:
+            # a trace (and its compile) ran inside the call iff note() rose
+            SPANS.close(sp, compiled=self._trace_counts.get(c.key, 0)
+                        > n_traces)
         match_pos = match_len = None
         if c.key.mode in POSITIONAL_MODES:
             match_pos, match_len = res.match_pos, res.match_len
         if reg.enabled:
-            self._record_search(reg, c.key, res, c.key.batch_shape, t0)
+            sp = SPANS.open("engine.record") if SPANS.enabled else None
+            self._record_search(reg, c.key, res, c.key.batch_shape[0], t0)
+            if sp is not None:
+                SPANS.close(sp)
         return SearchResults(docs=res.docs, scores=res.scores,
                              n_found=res.n_found, work=res.iters, k=c.key.k,
                              mode=c.key.mode, strategy=c.key.strategy,
@@ -710,60 +724,41 @@ class SearchEngine:
                     self._avg_doc_len())
         return types.SimpleNamespace(key=key, ex=ex, args=args, sla=sla)
 
-    def _record_search(self, reg: "obs.Registry", key, res, shape, t0):
-        """Registry side of one observed search (enabled registries only):
-        per-(backend, strategy, mode) dispatch counters, per-row work
-        histograms (trips/pops/pad-waste), and the live WTBC query-roofline
-        gauges.  Forces device completion first — the wall time must cover
-        the compute, not just its dispatch — which is why the disabled path
-        skips this method entirely (DESIGN.md §10 overhead budget)."""
-        jax.block_until_ready(res.docs)
-        dt = time.perf_counter() - t0
-        B, Q = int(shape[0]), int(shape[1])
+    def _record_search(self, reg: "obs.Registry", key, res, B, t0):
+        """Registry side of one search (enabled registries only):
+        per-(backend, strategy, mode) dispatch counters and per-row work
+        histograms (trips/pops/pad-waste).  Reading the per-row counts on
+        the host waits for the device (the ``engine.record`` span), which
+        is why the disabled path skips this method entirely (DESIGN.md §10
+        overhead budget)."""
         labels = {"backend": key.backend, "strategy": key.strategy,
                   "mode": key.mode}
         reg.counter("repro_engine_searches_total", labels,
                     "search batches dispatched").inc()
         reg.counter("repro_engine_rows_total", labels,
                     "query rows searched").inc(B)
-        reg.histogram("repro_engine_dispatch_seconds", labels,
-                      "blocking wall time per search batch").observe(dt)
         reg.gauge("repro_engine_executors", None,
                   "compiled executors cached").set(len(self._executors))
         work = np.asarray(res.iters).ravel()
+        reg.histogram("repro_engine_dispatch_seconds", labels,
+                      "wall time per search batch, to its work counts "
+                      "on the host").observe(time.perf_counter() - t0)
         reg.histogram("repro_engine_trips", labels,
                       "search-loop trips per query row"
                       ).observe_many(work.tolist())
         pops = getattr(res, "pops", None)
         padded = getattr(res, "padded", None)
         if pops is not None:
-            pops = np.asarray(pops).ravel()
             reg.histogram("repro_engine_pops", labels,
                           "candidate pops per query row"
-                          ).observe_many(pops.tolist())
-            if key.budget is None and len(pops):
-                # feed the deadline->budget estimator from *unbudgeted*
-                # batches only: a budget-cut batch's wall time hides the
-                # harvest tail and would bias us/pop optimistic
-                self.note_cost(dt, float(pops.mean()))
+                          ).observe_many(np.asarray(pops).ravel().tolist())
             reg.gauge("repro_engine_us_per_pop", None,
                       "live pop cost estimate feeding deadline budgets"
                       ).set(self.us_per_pop)
         if padded is not None:
-            padded = np.asarray(padded).ravel()
             reg.histogram("repro_engine_pad_lanes", labels,
                           "dead beam lanes per query row (pad waste)"
-                          ).observe_many(padded.tolist())
-        if pops is not None and len(pops):
-            from repro.analysis import roofline
-            rl = roofline.wtbc_query_roofline(
-                device_kind=jax.devices()[0].device_kind,
-                lowering=key.lowering,
-                measured_us_per_query=dt * 1e6 / max(B, 1),
-                pops=float(pops.mean()),
-                padded=float(padded.mean()) if padded is not None else 0.0,
-                q=Q, block=int(self.config.block))
-            roofline.live_wtbc_gauges(rl, reg)
+                          ).observe_many(np.asarray(padded).ravel().tolist())
 
     # -- post-processing -----------------------------------------------------
 

@@ -28,10 +28,11 @@ jittered-backoff retries on shed.  The CI ``anytime-smoke`` job drives
 these flags end to end.
 
 Observability (DESIGN.md §10): ``--metrics`` enables the process
-:mod:`repro.obs` registry (span timelines, per-stage histograms, live
-roofline gauges); ``--metrics-port N`` additionally serves Prometheus text
-at ``http://127.0.0.1:N/metrics`` (0 = ephemeral, the chosen port is
-printed) plus a JSON snapshot at ``/metrics.json``; ``--stats-every S``
+:mod:`repro.obs` registry and span recorder (spans of every batch and
+request, per-stage histograms fed from them); ``--metrics-port N``
+additionally serves Prometheus text at ``http://127.0.0.1:N/metrics`` (0 =
+ephemeral, the chosen port is printed) plus a JSON snapshot at
+``/metrics.json``; ``--stats-every S``
 appends one JSONL registry snapshot every S seconds to ``--stats-jsonl``
 (or stdout).  Any of the three implies ``--metrics``.
 """
@@ -149,8 +150,8 @@ def main():
                     help="exit 1 unless p99 is finite and nothing was shed")
     # observability
     ap.add_argument("--metrics", action="store_true",
-                    help="enable the repro.obs registry (span timelines, "
-                         "stage histograms, roofline gauges)")
+                    help="enable the repro.obs registry and span recorder "
+                         "(spans, stage histograms)")
     ap.add_argument("--metrics-port", type=int, default=None,
                     help="serve Prometheus text at /metrics on this port "
                          "(0 = ephemeral; implies --metrics)")
@@ -166,6 +167,8 @@ def main():
     metrics_on = (args.metrics or args.metrics_port is not None
                   or args.stats_every > 0)
     reg = obs.enable() if metrics_on else None
+    if metrics_on:
+        obs.enable_spans()
     metrics_http = None
     if args.metrics_port is not None:
         metrics_http = obs.MetricsServer(reg, port=args.metrics_port)
@@ -263,10 +266,14 @@ def main():
                 print(f"  {stage:10s} p50 {d['p50_ms']:.2f}ms  "
                       f"p95 {d['p95_ms']:.2f}ms  p99 {d['p99_ms']:.2f}ms  "
                       f"(n={d['count']})")
-        for g in reg.find("repro_roofline_achieved_frac"):
-            kind = dict(g.labels).get("device_kind", "?")
-            print(f"roofline[{kind}]: achieved fraction {g.value:.2e} of the "
-                  "memory-bandwidth floor")
+        log = obs.SPANS.drain()
+        by_name: dict[str, list[int]] = {}
+        for sp in log.spans:
+            by_name.setdefault(sp.name, []).append(sp.duration_ns)
+        print(f"spans: {len(log.spans)} kept, {log.dropped} dropped")
+        for name, ns in sorted(by_name.items()):
+            print(f"  {name:15s} n={len(ns)}  total {sum(ns) / 1e9:.3f}s  "
+                  f"max {max(ns) / 1e6:.2f}ms")
         emit_snapshot()
         if metrics_http is not None:
             metrics_http.close()

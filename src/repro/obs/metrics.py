@@ -1,8 +1,8 @@
 """Unified metrics: counters, gauges, and log2-sub-bucketed histograms.
 
 One :class:`Registry` per process (usually — :func:`default_registry`) holds
-every metric family the serving stack, the engine facade, the kernel
-dispatcher and the roofline attachment emit.  Design constraints, in order:
+every metric family the serving stack, the engine facade and the kernel
+dispatcher emit.  Design constraints, in order:
 
 * **Disabled is free.**  The registry starts disabled; every recording
   method's first action is one attribute load + branch on

@@ -188,10 +188,6 @@ class MicroBatcher:
         if len(r) == 4:                     # lane-less producers still work
             r = (*r, DEFAULT_LANE)
         self._pending.append(r)
-        if self._obs.enabled:
-            tl = getattr(r[2], "timeline", None)
-            if tl is not None:
-                tl.mark("lane_enqueue")
         now = self._clock()
         if self._last_arrival is not None:
             gap = now - self._last_arrival
@@ -263,10 +259,6 @@ class MicroBatcher:
                 "real rows per coalesced batch, by admission lane",
             ).observe(len(taken))
             self._m_wait.observe(self._clock() - taken[0][3])
-            for _, _, item, _, _ in taken:
-                tl = getattr(item, "timeline", None)
-                if tl is not None:
-                    tl.mark("batch_form")
         rows = [list(words) for words, _, _, _, _ in taken]
         return Batch(profile=group[0],
                      items=[item for _, _, item, _, _ in taken],

@@ -120,11 +120,13 @@ def _pcts(ms: np.ndarray) -> tuple[float, float, float]:
 
 def stage_breakdown(server: SearchServer) -> dict | None:
     """Registry-derived per-stage latency attribution (milliseconds): the
-    ``repro_request_stage_seconds`` histograms the server recorded, one entry
-    per stage (queue_wait / device / slice / total), each with reconstructed
-    p50/p95/p99, mean, and count.  None when the server's registry is
-    disabled or no stage was recorded — callers (table6/table7, BENCH)
-    emit the field only when observability was on."""
+    ``repro_request_stage_seconds`` histograms the server fed from its spans
+    (queue_wait = ``serve.queue``, device = dispatch to the end of
+    ``engine.wait``, slice = ``serve.fetch``, total = ``serve.request``),
+    each with reconstructed p50/p95/p99, mean, and count.  None when the
+    server's registry is disabled or no stage was recorded (the span
+    recorder was off) — callers (table6/table7, BENCH) emit the field only
+    when observability was on."""
     reg = getattr(server, "obs", None)
     if reg is None or not reg.enabled:
         return None

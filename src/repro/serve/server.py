@@ -27,15 +27,21 @@ Tail isolation (``work_buckets=True``): admission predicts per-query work
 from summed word document frequencies and batches only within factor-8 work
 lanes; predicted-heavy queries run alone (DESIGN.md §8).
 
-Observability (DESIGN.md §10): every request carries a span
-:class:`repro.obs.Timeline` (submit -> admit -> lane_enqueue -> batch_form
--> dispatch -> device -> slice -> complete) when the server's registry is
-enabled, and the server mirrors its counters plus per-stage latency
-histograms (queue-wait / device / slice / total) into that registry —
-``stats`` remains the dict-shaped compatibility view, now built from
-defensive snapshots so no reader can observe a mid-mutation engine or cache
-dict.  With the registry disabled (the default) no timeline is allocated
-and every recording call is a single checked no-op.
+Observability (DESIGN.md §10): with the process span recorder on
+(``repro.obs.enable_spans()``) the dispatch thread records each batch as
+spans (``serve.wait``, ``serve.batch`` and its children ``engine.prepare``,
+``engine.launch``, ``engine.wait``, ``serve.fetch``, ``serve.complete``) and
+each request as ``serve.request`` with its ``serve.queue``; the per-stage
+latency histograms (queue-wait / device / slice / total) of the server's
+registry are fed from those spans.  The server mirrors its counters into
+that registry — ``stats`` remains the dict-shaped compatibility view, built
+from defensive snapshots so no reader can observe a mid-mutation engine or
+cache dict.  With both off (the default) no span is allocated and every
+recording point is a single checked flag.
+
+The dispatch thread waits for a batch's device work once, in one place
+(``jax.block_until_ready`` on the whole result), whether spans are on or
+off.
 """
 from __future__ import annotations
 
@@ -44,11 +50,12 @@ import queue
 import threading
 import time
 
+import jax
 import numpy as np
 
 import repro.obs as obs
 from repro.engine.config import SLA_CLASSES
-from repro.obs.tracing import Timeline, stage_durations
+from repro.obs.tracing import SPANS
 from repro.runtime.fault_tolerance import StragglerWatchdog
 from repro.serve.batcher import (DEFAULT_LANE, Batch, Lane, MicroBatcher,
                                  QueryProfile, work_bucket)
@@ -119,11 +126,11 @@ class Ticket:
     recorded by the server (``latency_s`` spans submit -> completion,
     queue wait included — the number a client actually experiences; it
     decomposes exactly into :attr:`queue_wait_s` + :attr:`service_s`).
-    ``timeline`` is the span trace (None unless the server's obs registry
-    is enabled)."""
+    ``request_id`` is the ``ref_id`` of its spans (0 unless the span
+    recorder was on when it was served)."""
 
     __slots__ = ("words", "profile", "t_submit", "t_dispatch", "t_done",
-                 "cache_hit", "batch_size", "timeline", "degraded",
+                 "cache_hit", "batch_size", "request_id", "degraded",
                  "_event", "_result", "_error", "_lock")
 
     def __init__(self, words, profile):
@@ -135,7 +142,7 @@ class Ticket:
         self.cache_hit = False
         self.batch_size = 0
         self.degraded = False     # admission shrank the budget under load
-        self.timeline: Timeline | None = None
+        self.request_id = 0
         self._event = threading.Event()
         self._result = None
         self._error = None
@@ -155,8 +162,6 @@ class Ticket:
                 return False
             self._error = error
             self.t_done = time.monotonic()
-            if self.timeline is not None:
-                self.timeline.mark("complete", self.t_done)
             self._event.set()
             return True
 
@@ -179,8 +184,8 @@ class Ticket:
 
     @property
     def t_complete(self) -> float | None:
-        """Completion time (alias of ``t_done`` — the span taxonomy's name
-        for the terminal mark)."""
+        """Completion time (alias of ``t_done``: where ``serve.request``
+        ends)."""
         return self.t_done
 
     @property
@@ -202,15 +207,15 @@ class Ticket:
         t0 = self.t_submit if self.t_dispatch is None else self.t_dispatch
         return self.t_done - t0
 
-    def _complete(self, result=None, error=None):
+    def _complete(self, result=None, error=None) -> bool:
+        """Resolve with ``result`` or ``error``; False if a cancel won."""
         with self._lock:
             if self._event.is_set():      # lost the race to cancel()
-                return
+                return False
             self._result, self._error = result, error
             self.t_done = time.monotonic()
-            if self.timeline is not None:
-                self.timeline.mark("complete", self.t_done)
             self._event.set()
+            return True
 
 
 class SearchServer:
@@ -228,11 +233,12 @@ class SearchServer:
         engine's document count) run at batch size 1 so they never tax
         lighter batch-mates (DESIGN.md §8).  ``adaptive_wait`` collapses the
         coalescing wait to 0 while the arrival stream is idle.  ``registry``
-        is the :mod:`repro.obs` registry counters/histograms/span timelines
-        record into (default: the process registry, disabled unless
-        ``obs.enable()``/the CLI metrics flags turned it on); the engine is
-        pinned to the same registry (``engine.obs_registry``) so engine-side
-        counters land next to the serving ones."""
+        is the :mod:`repro.obs` registry counters and histograms record into
+        (default: the process registry, disabled unless ``obs.enable()``/the
+        CLI metrics flags turned it on); the engine is pinned to the same
+        registry (``engine.obs_registry``) so engine-side counters land next
+        to the serving ones.  Spans go to the process recorder
+        (``repro.obs.SPANS``)."""
         if queue_depth < 1:
             raise ValueError(f"queue_depth must be >= 1, got {queue_depth}")
         self.engine = engine
@@ -464,8 +470,6 @@ class SearchServer:
             with self._lock:
                 self.n_degraded += 1
             self._m_req["degraded"].inc()
-        if self.obs.enabled:
-            ticket.timeline = Timeline(ticket.t_submit)
         with self._lock:
             self.n_submitted += 1
         self._m_req["submitted"].inc()
@@ -478,7 +482,8 @@ class SearchServer:
                 self.n_served += 1
             self._m_req["served"].inc()
             self._m_req["cache_hit"].inc()
-            self._record_stages(ticket)
+            if SPANS.enabled:
+                self._record_request(ticket, SPANS.new_id())
             return ticket
         lane = self._lane_of(key)
         with self._lock:
@@ -490,8 +495,6 @@ class SearchServer:
             # counted before the put so a swap can never observe 0 while an
             # admitted request is still on its way to the dispatch thread
             self._n_inflight += 1
-        if ticket.timeline is not None:
-            ticket.timeline.mark("admit")
         try:
             self._queue.put_nowait((key, profile, ticket, time.monotonic(),
                                     lane))
@@ -556,31 +559,74 @@ class SearchServer:
     # -- dispatch thread -----------------------------------------------------
 
     def _run(self):
+        wait = None                 # the open serve.wait span, if any
+        t_end = None                # when the last recorded batch ended
         while self._running or not self._queue.empty() \
                 or self._batcher._pending:
+            if wait is None and SPANS.enabled:
+                # from the last batch's end, so the loop between two
+                # batches (and any wait for the interpreter lock) is in it
+                wait = SPANS.open("serve.wait", start_ns=t_end)
+            t_end = None
             batch = self._batcher.next_batch()
             if batch is not None:
-                self._dispatch(batch)
+                t_end = self._dispatch(batch, wait)
+                wait = None
+        if wait is not None:
+            SPANS.close(wait)
 
-    def _record_stages(self, ticket: Ticket) -> None:
-        """Fold one completed ticket's span timeline into the per-stage
-        latency histograms (no-op when the registry is disabled)."""
-        if ticket.timeline is None:
-            return
-        for stage, dt in stage_durations(ticket.timeline).items():
-            self._m_stage[stage].observe(dt)
+    def _record_request(self, ticket: Ticket, request_id: int,
+                        batch_id: int = 0, t_dispatch_ns: int = 0,
+                        t_ready_ns: int = 0, fetch_ns: int = 0) -> None:
+        """Record one completed request's spans (``serve.request``, and
+        ``serve.queue`` for a dispatched one) and feed the per-stage latency
+        histograms from them (spans on only)."""
+        ticket.request_id = request_id
+        t_submit = int(ticket.t_submit * 1e9)
+        t_done = int(ticket.t_done * 1e9)
+        span_id = SPANS.add("serve.request", t_submit, t_done,
+                            ref_id=request_id)
+        self._m_stage["total"].observe((t_done - t_submit) / 1e9)
+        if batch_id:
+            SPANS.add("serve.queue", t_submit, t_dispatch_ns,
+                      parent_id=span_id, ref_id=request_id,
+                      attrs={"batch": batch_id})
+            self._m_stage["queue_wait"].observe(
+                (t_dispatch_ns - t_submit) / 1e9)
+            self._m_stage["device"].observe(
+                (t_ready_ns - t_dispatch_ns) / 1e9)
+            self._m_stage["slice"].observe(fetch_ns / 1e9)
 
-    def _dispatch(self, batch: Batch):
-        t0 = time.monotonic()
+    def _dispatch(self, batch: Batch, wait=None) -> int | None:
+        """Serve one batch.  ``wait`` is the open ``serve.wait`` span that
+        ended with it: given, the batch is recorded as spans, and the end
+        of ``serve.batch`` is returned."""
+        spans = wait is not None
+        if spans:
+            batch_id = SPANS.new_id()
+            t0_ns = SPANS.close(wait, ref_id=batch_id)
+            sp_batch = SPANS.open(
+                "serve.batch", ref_id=batch_id, start_ns=t0_ns,
+                attrs={"n_real": batch.n_real,
+                       "padded": len(batch.queries) - batch.n_real})
+            t0 = t0_ns / 1e9
+        else:
+            t0 = time.monotonic()
         for t in batch.items:
             t.t_dispatch = t0
-            if t.timeline is not None:
-                t.timeline.mark("dispatch", t0)
-        for t in batch.items:
             t.batch_size = batch.n_real
         try:
             res = self.engine.search(batch.queries,
                                      **batch.profile.search_kwargs())
+            sp = SPANS.open("engine.wait") if spans else None
+            # the one wait for the batch's device work: every host read
+            # below finds its values ready
+            jax.block_until_ready(getattr(res, "__dict__", res))
+            if spans:
+                t_ready_ns = SPANS.close(sp)
+                t_ready = t_ready_ns / 1e9
+            else:
+                t_ready = time.monotonic()
         except Exception as e:                    # profile-level failure
             for t in batch.items:
                 t._complete(error=e)
@@ -588,21 +634,20 @@ class SearchServer:
             with self._lock:
                 self.n_errors += batch.n_real
                 self._n_inflight -= batch.n_real
-            return
-        if self.obs.enabled:
-            # force device completion so the device/slice split is real
-            # (values are unchanged — DESIGN.md §10 exactness argument)
-            np.asarray(res.docs)
-            t_dev = time.monotonic()
-            for t in batch.items:
-                if t.timeline is not None:
-                    t.timeline.mark("device", t_dev)
-        dt = time.monotonic() - t0
+            if spans:
+                return SPANS.close(sp_batch, error=type(e).__name__)
+            return None
+        dt = t_ready - t0
         self._step += 1
         if self._watchdog.observe(self._step, dt):
             with self._lock:
                 self.n_stragglers += 1
             self._m_straggler.inc()
+        sp = SPANS.open("serve.fetch") if spans else None
+        rows = _slice_rows(res, batch.n_real)
+        if spans:
+            fetch_ns = SPANS.close(sp) - sp.start_ns
+            sp = SPANS.open("serve.complete")
         # feed the engine's deadline->budget estimator from *unbudgeted*
         # batches (a budget-cut batch would bias the pop cost optimistic)
         pops_arr = getattr(res, "pops", None)
@@ -612,17 +657,14 @@ class SearchServer:
                 p = np.asarray(pops_arr).ravel()
                 if len(p):
                     note(dt, float(p.mean()))
-        rows = _slice_rows(res, batch.n_real)
-        if self.obs.enabled:
-            t_slice = time.monotonic()
-            for t in batch.items:
-                if t.timeline is not None:
-                    t.timeline.mark("slice", t_slice)
         n_over = n_pad = 0
+        request_ids = []
         for t, row in zip(batch.items, rows):
             self.cache.put((t.words, t.profile, self._tag), row)
-            t._complete(result=row)
-            self._record_stages(t)
+            if t._complete(result=row) and spans:
+                request_ids.append(SPANS.new_id())
+                self._record_request(t, request_ids[-1], batch_id, t0_ns,
+                                     t_ready_ns, fetch_ns)
             n_over += bool(row.overflowed)
             n_pad += row.padded or 0
         self._m_req["served"].inc(batch.n_real)
@@ -637,6 +679,10 @@ class SearchServer:
             self.batch_hist[batch.n_real] = \
                 self.batch_hist.get(batch.n_real, 0) + 1
             self.dispatch_s += dt
+        if not spans:
+            return None
+        end = SPANS.close(sp)
+        return SPANS.close(sp_batch, end_ns=end, requests=request_ids)
 
     # -- introspection -------------------------------------------------------
 
