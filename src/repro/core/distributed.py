@@ -247,11 +247,9 @@ def distributed_topk(sharded: ShardedWTBC, words: jnp.ndarray, wmask: jnp.ndarra
         global_avg_dl=P(),
         n_shards=sharded.n_shards)
     in_specs = (sharded_specs, P(), P(), P())
-    # drb-or is the one loop-free method whose core reports no pad-waste
-    # lane count; every other method threads `padded` through the merge so
-    # the serving/obs layer sees the same diagnostics sharded as single-host
-    has_pad = method != "drb-or"
-    out_specs = (P(),) * (9 if has_pad else 8)
+    # every method threads `padded` through the merge so the serving/obs
+    # layer sees the same diagnostics sharded as single-host
+    out_specs = (P(),) * 9
 
     def local(sh: ShardedWTBC, words, wmask, idf_tab):
         batched = words.ndim == 2                      # (B, Q) query batches
@@ -324,8 +322,7 @@ def distributed_topk(sharded: ShardedWTBC, words: jnp.ndarray, wmask: jnp.ndarra
             pops = jax.lax.psum(pops, ax)
             over = jax.lax.psum(over, ax)
             bound = jax.lax.pmax(bound, ax)
-            if has_pad:
-                padded = jax.lax.psum(padded, ax)
+            padded = jax.lax.psum(padded, ax)
         # certification is strict-score vs the global *pending* bound (see
         # the docstring); the reported bound additionally covers the docs
         # the merge itself dropped
@@ -334,12 +331,11 @@ def distributed_topk(sharded: ShardedWTBC, words: jnp.ndarray, wmask: jnp.ndarra
         bound_out = jnp.maximum(bound, dropped_s)
         out = (jnp.where(top_s > -jnp.inf, top_d, -1), top_s, n_found, iters,
                pops, over > 0, certified, bound_out)
-        return out + (padded,) if has_pad else out
+        return out + (padded,)
 
     fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
     res = fn(sharded, words, wmask, idf)
-    docs, scores, n_found, iters, pops, over, certified, bound = res[:8]
+    docs, scores, n_found, iters, pops, over, certified, bound, padded = res
     return ranked.DRResult(docs, scores, n_found, iters, pops, over,
-                           padded=res[8] if has_pad else None,
-                           certified=certified, bound=bound)
+                           padded=padded, certified=certified, bound=bound)

@@ -268,6 +268,11 @@ def topk_drb_or(idx: WTBCIndex, aux: DRBAux, words: jnp.ndarray,
     TPU adaptation: the per-word walk is a padded (Q, max_df_cap) gather and
     the aggregation is one scatter-add into a document-score table + one
     ``lax.top_k`` — replacing the paper's sort-merge with dense vector ops.
+    The (Q, max_df_cap) first-occurrence locates go down flat in one
+    ``wtbc.locate_batch`` call (one ``wavelet_locate`` launch on TPU), with
+    j = 0 on the dead lanes (past the word's df, or a masked / stopword
+    slot), which the kernel skips.  ``padded`` counts those dead lanes,
+    ``Q * max_df_cap`` less the live ones.
     ``max_df_cap`` must be >= max document frequency among the query words.
     """
     Q = words.shape[0]
@@ -284,7 +289,6 @@ def topk_drb_or(idx: WTBCIndex, aux: DRBAux, words: jnp.ndarray,
 
     def per_word(q):
         w = words[q]
-        live = (js < df_w[q]) & valid[q]
         # one select1 per document: hoist the word's bitmap base rank (was
         # recomputed per j) and diff consecutive selects instead of running a
         # second select pass for the next-1 positions (§Perf hillclimb 3:
@@ -296,12 +300,18 @@ def topk_drb_or(idx: WTBCIndex, aux: DRBAux, words: jnp.ndarray,
         )(jnp.arange(max_df_cap + 1, dtype=jnp.int32))                     # (cap+1,)
         sel = sels[:-1]                                                    # i_j
         tf = jnp.where(js + 1 < df_w[q], sels[1:], occ_w[q]) - sel
-        first_occ = jax.vmap(lambda i: wtbc.locate(idx, w, i + 1))(sel)
-        d = jax.vmap(lambda pp: wtbc.doc_of_pos(idx, pp))(first_occ)
-        d = jnp.where(live, d, n_docs_static)                              # OOB drop
-        return d, jnp.where(live, tf, 0)
+        return sel, tf
 
-    docs_m, tf_m = jax.vmap(per_word)(jnp.arange(Q))                       # (Q, cap)
+    sel_m, tf_m = jax.vmap(per_word)(jnp.arange(Q))                        # (Q, cap)
+    live = (js[None, :] < df_w[:, None]) & valid[:, None]
+    # the document's first occurrence is the (i_j + 1)-th of the word
+    first_occ = wtbc.locate_batch(
+        idx, jnp.repeat(words, max_df_cap),
+        jnp.where(live, sel_m + 1, 0).reshape(-1)).reshape(Q, max_df_cap)
+    docs_m = jnp.where(live, wtbc.doc_of_pos(idx, first_occ),
+                       n_docs_static)                                      # OOB drop
+    tf_m = jnp.where(live, tf_m, 0)
+    padded = Q * max_df_cap - jnp.sum(live.astype(jnp.int32))
 
     # per-(word, doc) tf table -> additive measures need tf before transform
     tf_table = jnp.zeros((Q, n_docs_static + 1), jnp.int32)
@@ -315,5 +325,5 @@ def topk_drb_or(idx: WTBCIndex, aux: DRBAux, words: jnp.ndarray,
     # loop-free dense pass: always exhaustive, hence always fully certified
     return DRResult(jnp.where(top_s > -jnp.inf, top_d, -1).astype(jnp.int32),
                     top_s.astype(jnp.float32), found, jnp.int32(max_df_cap),
-                    jnp.int32(max_df_cap), jnp.zeros((), bool),
+                    jnp.int32(max_df_cap), jnp.zeros((), bool), padded,
                     certified=top_s > -jnp.inf, bound=H.NEG_INF)

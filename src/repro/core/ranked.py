@@ -84,8 +84,8 @@ class DRResult(NamedTuple):
     overflowed: jnp.ndarray | None = None
     # () int32 — dead pop lanes whose descent rows were still computed
     # (pad-waste): pops + padded = beam lanes processed.  The active-frontier
-    # buckets keep this near zero; None on cores without beam padding (mega,
-    # brute force, sharded merge).
+    # buckets keep this near zero; DRB/OR counts its dead (word, document)
+    # lanes; None on cores without padding (mega, brute force).
     padded: jnp.ndarray | None = None
     # (k,) bool — anytime certification (DESIGN.md §11): slot i is certified
     # iff its key lex-beats the pending bound at the stopping point, i.e. it

@@ -289,30 +289,49 @@ def count_doc(idx: WTBCIndex, w: jnp.ndarray, d: jnp.ndarray) -> jnp.ndarray:
     return count_range(idx, w, lo, hi)
 
 
-def locate(idx: WTBCIndex, w: jnp.ndarray, j: jnp.ndarray) -> jnp.ndarray:
-    """Root position of the j-th (1-based) occurrence of word-rank w.
+def locate_walk(level_select, cwb, off, base, cwl, j, occ, n):
+    """The leaf -> root walk of locate, one select per level (paper §2.2
+    'locating'), shared by the scalar path, the batched oracle and the
+    ``wavelet_locate`` kernel so they cannot drift apart.
 
-    Walks leaf -> root with one select per level (paper §2.2 'locating').
-    Out-of-range ``j`` (j < 1 or j > occ[w]) is not checked here: each level's
-    ``bytemap.select`` saturates to its stream length, so the walk returns a
-    position >= the word's last occurrence — typically ``idx.n`` — but callers
-    that cannot guarantee ``1 <= j <= idx.occ[w]`` must validate ``j``
-    themselves before trusting the result.
-    """
-    # start: at the leaf level (len-1) the j-th occurrence of w corresponds to
-    # the (base_rank + j)-th occurrence of its stopper byte in that level.
+    ``level_select(L, byte, k)`` is the lowering's ``bytemap.select`` on level
+    L: the position of the k-th (1-based) occurrence of ``byte``, the level's
+    length when k is out of range.  The walk passes k = 0 for levels the word
+    does not reach and for a dead ``j`` (j < 1 or j > ``occ``), so a lowering
+    may skip the work there; a dead ``j`` returns ``n``."""
+    live = (j >= 1) & (j <= occ)
     pos = jnp.int32(0)
     for L in range(MAX_LEVELS - 1, -1, -1):
-        byte = idx.cw[w, L]
-        off = idx.node_off[w, L]
-        base = idx.base_rank[w, L]
-        is_leaf = idx.cw_len[w] == (L + 1)
-        active = idx.cw_len[w] > L
-        # occurrence index within this level's byte stream (global, 1-based)
-        occ_idx = jnp.where(is_leaf, base + j, base + pos + 1)
-        p = bytemap.select(idx.levels[L], byte, occ_idx) - off
+        active = live & (cwl > L)
+        # occurrence index within this level's byte stream (global, 1-based):
+        # at the leaf (level cwl-1) the j-th occurrence of w is the
+        # (base + j)-th occurrence of its stopper byte there
+        k = jnp.where(cwl == L + 1, base[L] + j, base[L] + pos + 1)
+        p = level_select(L, cwb[L], jnp.where(active, k, 0)) - off[L]
         pos = jnp.where(active, p, pos)
-    return pos.astype(jnp.int32)
+    return jnp.where(live, pos, n).astype(jnp.int32)
+
+
+def locate(idx: WTBCIndex, w: jnp.ndarray, j: jnp.ndarray) -> jnp.ndarray:
+    """Root position of the j-th (1-based) occurrence of word-rank w;
+    ``idx.n`` when j < 1 or j > occ[w]."""
+    return locate_walk(
+        lambda L, byte, k: bytemap.select(idx.levels[L], byte, k),
+        idx.cw[w].astype(jnp.int32), idx.node_off[w], idx.base_rank[w],
+        idx.cw_len[w], j, idx.occ[w], idx.n)
+
+
+def locate_batch(idx: WTBCIndex, words: jnp.ndarray,
+                 js: jnp.ndarray) -> jnp.ndarray:
+    """Batched locate: root position of the ``js[i]``-th occurrence of
+    ``words[i]`` for a flat batch of M pairs; (M,) int32, ``idx.n`` for a
+    dead pair (j < 1 or j > occ).  One ``wavelet_locate`` Pallas launch on
+    TPU, which spends nothing on dead pairs; the vmapped walk elsewhere
+    (see ``kernels.ops.wavelet_locate_batch``)."""
+    from repro.kernels import ops
+    return ops.wavelet_locate_batch(idx.levels, idx.cw, idx.cw_len,
+                                    idx.node_off, idx.base_rank, idx.occ,
+                                    idx.n, words, js)
 
 
 def decode_at(idx: WTBCIndex, pos: jnp.ndarray) -> jnp.ndarray:

@@ -41,8 +41,9 @@ class SearchResults:
              trusted silently.  See :meth:`diagnostics`.
     padded:  (B,) int32 — dead beam lanes processed (pad-waste): pops +
              padded = lanes the loop actually paid for.  The active-frontier
-             buckets (core/ranked.py) keep this near zero; None on paths
-             without beam padding.
+             buckets (core/ranked.py) keep this near zero.  DRB/OR: its
+             dead (word, document) lanes, Q x df_cap less the live ones.
+             None on paths without padding.
     certified: (B, k) bool — anytime certification (DESIGN.md §11): a True
              slot provably equals the exact oracle's slot; always a prefix
              per row, and all-True whenever the search ran to completion.

@@ -26,6 +26,7 @@ from repro.kernels import byte_rank as _byte_rank_k
 from repro.kernels import bitmap_rank as _bitmap_rank_k
 from repro.kernels import topk_score as _topk_score_k
 from repro.kernels import wavelet_descent as _wavelet_descent_k
+from repro.kernels import wavelet_locate as _wavelet_locate_k
 from repro.kernels import ref
 
 _STATE = {"enabled": True}
@@ -99,6 +100,24 @@ def wavelet_count_batch(levels, cw, cw_len, node_off, base_rank,
             block=levels[0].block, lowering=plan.tag)
     return ref.wavelet_count_ref(levels, cw, cw_len, node_off, base_rank,
                                  words, los, his)
+
+
+def wavelet_locate_batch(levels, cw, cw_len, node_off, base_rank, occ, n,
+                         words, js) -> jnp.ndarray:
+    """Batched locate: root position of the ``js[i]``-th occurrence of
+    ``words[i]``, ``n`` for a dead pair (j < 1 or j > occ).
+
+    Under a ``tpu`` plan (``backend.descent_plan()``, ``tpu:interpret``
+    included) ONE ``wavelet_locate`` launch, which skips dead pairs; there is
+    no Triton lowering, so every other plan, and ``use_kernels(False)``, runs
+    the vmapped walk (``ref.wavelet_locate_ref``)."""
+    plan = backend.descent_plan() if _STATE["enabled"] else None
+    if plan is not None and plan.kind == "tpu":
+        return _wavelet_locate_k.batched_locate(
+            levels[0].block, plan.interpret)(
+                levels, cw, cw_len, node_off, base_rank, occ, n, words, js)
+    return ref.wavelet_locate_ref(levels, cw, cw_len, node_off, base_rank,
+                                  occ, n, words, js)
 
 
 def segment_tf_batch(bm: ByteMap, byte, bounds) -> "jnp.ndarray":

@@ -8,7 +8,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core import bytemap
+from repro.core import bytemap, wtbc
 from repro.core.bitvec import WORDS_PER_BLOCK
 
 
@@ -78,3 +78,17 @@ def wavelet_count_ref(levels, cw, cw_len, node_off, base_rank,
         res = jnp.where(is_leaf, rb - ra, res)
         a, b = ra, rb
     return res
+
+
+def wavelet_locate_ref(levels, cw, cw_len, node_off, base_rank, occ, n,
+                       words, js) -> jnp.ndarray:
+    """Batched locate, pure jnp: ``wtbc.locate_walk`` over ``bytemap.select``
+    vmapped over the M pairs (every lane pays its selects, dead ones too).
+    Oracle for the ``wavelet_locate`` kernel and its path off the TPU."""
+    def one(w, j):
+        return wtbc.locate_walk(
+            lambda L, byte, k: bytemap.select(levels[L], byte, k),
+            cw[w].astype(jnp.int32), node_off[w], base_rank[w], cw_len[w],
+            j, occ[w], n)
+
+    return jax.vmap(one)(words.astype(jnp.int32), js.astype(jnp.int32))

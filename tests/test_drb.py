@@ -106,3 +106,56 @@ def test_drb_bitmap_semantics(small_index, small_aux, small_corpus):
         d = int(wtbc.doc_of_pos(idx, jnp.int32(p)))
         got.append((d, tf))
     assert got == want
+
+
+@pytest.mark.parametrize("measure", ["tfidf", "bm25"])
+def test_drb_or_locate_kernel_matches_ref(small_index, small_aux, measure):
+    """DRB/OR with its locates in the ``wavelet_locate`` kernel (TPU body
+    under the interpreter) equals the jnp path bitwise and brute force;
+    ``padded`` counts the dead (word, lane) lanes, ``Q * df_cap`` less the
+    valid words' df."""
+    from repro.kernels import backend
+
+    idx, model = small_index
+    m = scoring.TfIdf() if measure == "tfidf" else scoring.BM25()
+    rng = np.random.default_rng(41)
+    df = np.asarray(idx.df)
+    has_bm = np.asarray(small_aux.has_bm)
+    for trial in range(3):
+        words = jnp.asarray(query_pool(idx, rng, 4), jnp.int32)
+        wmask = jnp.asarray([True, True, True, trial != 1])
+        cap = int(df[np.asarray(words)].max()) + 2
+        ref_res = drb.topk_drb_or(idx, small_aux, words, wmask, m, k=10,
+                                  max_df_cap=cap)
+        with backend.force_plan("tpu:interpret"):
+            kern = drb.topk_drb_or(idx, small_aux, words, wmask, m, k=10,
+                                   max_df_cap=cap)
+        for name in ("docs", "scores", "n_found", "padded"):
+            np.testing.assert_array_equal(np.asarray(getattr(kern, name)),
+                                          np.asarray(getattr(ref_res, name)),
+                                          err_msg=name)
+        valid = np.asarray(wmask) & has_bm[np.asarray(words)]
+        assert int(kern.padded) == 4 * cap - int(df[np.asarray(words)][valid]
+                                                 .sum())
+        bf = bruteforce_measure(idx, words, wmask, m, 10, conjunctive=False)
+        check_topk_equal(bf, kern)
+
+
+def test_drb_or_padded_in_diagnostics(engine, query_batch):
+    """The engine carries DRB/OR's dead lanes into ``diagnostics`` and the
+    ``repro_engine_pad_lanes`` histogram."""
+    import repro.obs as obs
+
+    reg = obs.Registry(enabled=True)
+    with obs.use(reg):
+        res = engine.search(query_batch, mode="or", strategy="drb",
+                            measure="bm25", k=5, df_cap=64)
+    pad = res.diagnostics["padded"]
+    df = np.asarray(engine.idx.df)
+    ranks = np.asarray(engine.model.rank_of_word)[np.asarray(query_batch)]
+    has_bm = np.asarray(engine.aux.has_bm)
+    q = 1 << (ranks.shape[1] - 1).bit_length()     # the pow2 Q bucket
+    want = [q * 64 - int(df[r][has_bm[r]].sum()) for r in ranks]
+    np.testing.assert_array_equal(pad, want)
+    h = reg.find("repro_engine_pad_lanes")[0]
+    assert h.n == len(want) and h.total == float(sum(want))

@@ -626,7 +626,7 @@ def test_diagnostics_thread_mega_path(obs_engine, obs_queries):
 @pytest.mark.slow
 def test_padded_threads_sharded_path(obs_corpus):
     """n_shards=1 on the single CPU device: the sharded merge must psum and
-    return padded (DR/DRB-AND), and report None only for DRB/OR."""
+    return padded for every method, DRB/OR's dead lanes included."""
     eng = SearchEngine.shard(obs_corpus, n_shards=1,
                              config=EngineConfig(block=512))
     qs = loadgen.sample_queries(eng, 4, 2, seed=5)
@@ -639,8 +639,10 @@ def test_padded_threads_sharded_path(obs_corpus):
                                   np.asarray(sres.padded))
     rows = _slice_rows(res, 4)
     assert all(r.padded is not None for r in rows)
-    assert _slice_rows(eng.search(qs, k=5, mode="or", strategy="drb",
-                                  measure="bm25"), 4)[0].padded is None
+    drb_kw = dict(k=5, mode="or", strategy="drb", measure="bm25")
+    np.testing.assert_array_equal(
+        [r.padded for r in _slice_rows(eng.search(qs, **drb_kw), 4)],
+        np.asarray(single.search(qs, **drb_kw).padded))
 
 
 # ---------------------------------------------------------------------------
